@@ -44,6 +44,8 @@ import time
 
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
+
 
 def _emit(name, us, derived):
     print(f"{name},{us:.1f},{derived}", flush=True)
@@ -62,6 +64,7 @@ def main() -> None:
                          "still written")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     quick = not args.full
     smoke = args.smoke
     results = {}

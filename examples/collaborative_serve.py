@@ -78,6 +78,7 @@ from repro.core.compressor import init_autoencoder
 from repro.core.split import transformer_split_table
 from repro.env.channel import channel_gain, uplink_rates
 from repro.kernels import ops as kops
+from repro.launch.cache import enable_compile_cache
 from repro.models import apply_model, init_params
 from repro.models.layers import apply_norm
 from repro.models.model import _logits, _run_stack, layer_plan
@@ -442,6 +443,7 @@ def main():
                          "device_count=K before launch; implies --fleet)")
     ap.add_argument("--iterations", type=int, default=15)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.entity_policy and args.shared_policy:
         ap.error("pick one of --entity-policy / --shared-policy")

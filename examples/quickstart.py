@@ -14,6 +14,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config
 from repro.core.split import transformer_split_table
 from repro.env.mecenv import MECEnv, make_env_params
+from repro.launch.cache import enable_compile_cache
 from repro.rl.baselines import local_policy_eval
 from repro.rl.mahppo import MAHPPOConfig, evaluate_policy, train_mahppo
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--iterations", type=int, default=30)
     ap.add_argument("--n-ue", type=int, default=5)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     plan = transformer_split_table(cfg)

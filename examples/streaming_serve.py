@@ -33,6 +33,7 @@ import argparse
 from repro.core.fleets import (make_edge_pool, make_mixed_fleet,
                                random_pool_ranges)
 from repro.env.mecenv import MECEnv, make_env_params
+from repro.launch.cache import enable_compile_cache
 from repro.rl.mahppo import MAHPPOConfig, train_mahppo
 from repro.rl.streaming import StreamTuneConfig, finetune_streaming
 from repro.stream.adapter import (EntityDispatcher, LocalDispatcher,
@@ -72,6 +73,7 @@ def main():
                     help="streaming DAgger fine-tune iterations "
                          "(0 = deploy zero-shot)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"training the entity policy: {args.iters} MAHPPO iterations on "
           f"the frame env (N={args.ues}, randomized "

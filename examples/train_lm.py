@@ -14,6 +14,7 @@ import numpy as np
 from repro.ckpt import load_checkpoint, save_checkpoint
 from repro.configs import get_config
 from repro.data.synthetic import TokenPipelineConfig, token_batch_stream
+from repro.launch.cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import init_params
 
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--out", default="artifacts/train_lm")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("qwen3-1.7b").replace(
         n_layers=args.layers, d_model=args.d_model, n_heads=12, n_kv_heads=4,

@@ -18,10 +18,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.quant import code_scale, to_codes
 
 
-def _kernel(x_ref, w_ref, mn_ref, mx_ref, o_ref, acc_ref, *, bits, nk):
+def _kernel(x_ref, w_ref, mn_ref, scale_ref, o_ref, acc_ref, *, bits, nk):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -34,16 +34,14 @@ def _kernel(x_ref, w_ref, mn_ref, mx_ref, o_ref, acc_ref, *, bits, nk):
 
     @pl.when(k == nk - 1)
     def _store():
-        mn = mn_ref[0, 0]
-        mx = mx_ref[0, 0]
         levels = float((1 << bits) - 1)
-        scale = levels / jnp.maximum(mx - mn, 1e-12)
-        y = jnp.clip(jnp.round((acc_ref[...] - mn) * scale), 0.0, levels)
-        o_ref[...] = y.astype(o_ref.dtype)
+        y = jnp.clip(jnp.round((acc_ref[...] - mn_ref[0, 0])
+                               * scale_ref[0, 0]), 0.0, levels)
+        o_ref[...] = to_codes(y, o_ref.dtype)
 
 
 def bottleneck_encode(x, w, mn, mx, *, bits=8, block=(256, 128, 512),
-                      interpret=True):
+                      interpret):
     """x: (T, d); w: (d, d'); mn/mx: calibrated quantization range.
     Returns uint8 codes (T, d')."""
     t, d = x.shape
@@ -66,7 +64,7 @@ def bottleneck_encode(x, w, mn, mx, *, bits=8, block=(256, 128, 512),
         out_shape=jax.ShapeDtypeStruct((t, dp), jnp.uint8 if bits <= 8
                                        else jnp.uint16),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, w, scal(mn), scal(mx))
+    )(x, w, scal(mn), scal(code_scale(mn, mx, bits)))

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -56,7 +55,7 @@ def _kernel(idx_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                        jnp.maximum(l_ref[...], 1e-20)).astype(o_ref.dtype)
 
 
-def decode_attention(q, k, v, pos, idx, *, block_s=512, interpret=True):
+def decode_attention(q, k, v, pos, idx, *, block_s=512, interpret):
     """q: (B, Hq, D); k, v: (B, S, Hkv, D); pos: (B, S) int32; idx: scalar.
     Returns (B, Hq, D) f32."""
     b, hq, d = q.shape
@@ -81,7 +80,7 @@ def decode_attention(q, k, v, pos, idx, *, block_s=512, interpret=True):
         scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(idx2, qr, k, v, pos)

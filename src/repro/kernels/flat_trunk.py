@@ -32,27 +32,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.quant import code_step, from_codes
 
 
-def _trunk_kernel(*refs, n_layers, bits):
+def _dot(a, b):
+    """f32 matmul at full precision, so the compiled kernel meets the f32
+    oracle as interpret mode does."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _trunk_kernel(*refs, n_layers):
     x_ref, o_ref = refs[0], refs[-1]
-    levels = float((1 << bits) - 1)
     h = x_ref[...].astype(jnp.float32)
     for i in range(n_layers):
-        codes_ref, mn_ref, mx_ref, b_ref = refs[1 + 4 * i:5 + 4 * i]
-        mn = mn_ref[0, 0]
-        mx = mx_ref[0, 0]
-        w = codes_ref[...].astype(jnp.float32) * ((mx - mn) / levels) + mn
-        h = jnp.dot(h, w, preferred_element_type=jnp.float32) + b_ref[...]
+        codes_ref, mn_ref, step_ref, b_ref = refs[1 + 4 * i:5 + 4 * i]
+        w = from_codes(codes_ref[...]) * step_ref[0, 0] + mn_ref[0, 0]
+        h = _dot(h, w) + b_ref[...]
         if i < n_layers - 1:
             h = jnp.tanh(h)
     o_ref[...] = h
 
 
 def flat_trunk_pallas(x, codes, mns, mxs, bs, *, bits=8, block_n=512,
-                      interpret=True):
+                      interpret):
     """Fused quantized trunk forward -> (M, W) f32 head columns.
 
     x: (M, F) feature rows (any float dtype); codes: per-layer integer
@@ -74,15 +79,15 @@ def flat_trunk_pallas(x, codes, mns, mxs, bs, *, bits=8, block_n=512,
         in_specs += [full((nin, nout)), full((1, 1)), full((1, 1)),
                      full((1, nout))]
         args += [codes[i], jnp.asarray(mns[i], f32).reshape(1, 1),
-                 jnp.asarray(mxs[i], f32).reshape(1, 1),
+                 code_step(mns[i], mxs[i], bits).reshape(1, 1),
                  jnp.asarray(bs[i], f32).reshape(1, nout)]
     return pl.pallas_call(
-        functools.partial(_trunk_kernel, n_layers=n_layers, bits=bits),
+        functools.partial(_trunk_kernel, n_layers=n_layers),
         grid=grid,
         in_specs=in_specs,
         out_specs=row(width),
         out_shape=jax.ShapeDtypeStruct((m, width), f32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)
@@ -93,13 +98,11 @@ def flat_trunk_xla(x, codes, mns, mxs, bs, *, bits=8):
     association as the kernel (``codes * ((mx - mn) / levels) + mn``), so
     the two impls agree bitwise on the dequantized weights."""
     f32 = jnp.float32
-    levels = float((1 << bits) - 1)
     h = x.astype(f32)
     n_layers = len(codes)
     for i in range(n_layers):
-        mn = jnp.asarray(mns[i], f32)
-        mx = jnp.asarray(mxs[i], f32)
-        w = codes[i].astype(f32) * ((mx - mn) / levels) + mn
+        w = codes[i].astype(f32) * code_step(mns[i], mxs[i], bits) \
+            + jnp.asarray(mns[i], f32)
         h = h @ w + jnp.asarray(bs[i], f32)
         if i < n_layers - 1:
             h = jnp.tanh(h)

@@ -1,13 +1,11 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on a real TPU
-set REPRO_PALLAS_INTERPRET=0 (or rely on backend autodetection) to compile
-them. Wrappers handle shape normalization (flattening leading dims, padding
-to block multiples where required).
+A Pallas kernel compiles for the TPU and runs in interpret mode only on the
+CPU backend (the tests). Wrappers handle shape normalization (flattening
+leading dims, padding to block multiples where required).
 """
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
@@ -19,10 +17,7 @@ from repro.kernels import quant as _q
 
 
 def _interpret_default():
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _impl_default(env_var):
@@ -145,4 +140,4 @@ def pair_scorer(ue_emb, raw, srv_enc, scorer, *, impl=None, interpret=None):
     if impl != "pallas":
         raise ValueError(f"unknown pair_scorer impl {impl!r}")
     interpret = _interpret_default() if interpret is None else interpret
-    return _ps.pair_scorer_pallas(*args, interpret=interpret)
+    return _ps.pair_scorer_fused(interpret, *args)

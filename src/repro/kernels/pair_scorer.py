@@ -31,7 +31,8 @@ pallas:
 ``pair_scorer_xla`` is the same decomposed computation expressed in
 plain jnp — the fast path on CPU/GPU hosts (and the thing the bench
 races against ``ref.pair_scorer_ref``'s naive materialized build). The
-Pallas kernel runs compiled on TPU and in interpret mode elsewhere. Both
+Pallas kernel runs compiled on TPU and in interpret mode on the CPU;
+``pair_scorer_fused`` gives it the XLA form's gradient for training. Both
 match ``kernels.ref.pair_scorer_ref`` to fp32 tolerance; ``active``
 feeds ONLY the occupancy reduction (the default path scores inactive
 rows too and masks at the action level), so churn parity is exact by
@@ -44,13 +45,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 # consts-vector layout (see module docstring / MECEnv._scorer_consts)
 C_PATHLOSS, C_PMAX, C_SIGMA, C_RATE_SCALE = 0, 1, 2, 3
 C_T0, C_SLOT_DIV, C_DIST_NORM, C_SLOW_INV = 4, 5, 6, 7
 N_CONSTS = 8
+
+
+def _dot(a, b):
+    """f32 matmul at full precision, so the compiled kernel meets the f32
+    oracle as interpret mode does."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def _edge_cols(d, work, g0, g1, g2, consts):
@@ -84,34 +92,29 @@ def _scorer_kernel(consts_ref, geom_ref, act_ref, ue_ref, d_ref, work_ref,
     b1 = b1_ref[...]                                    # (1, 48)
     # the ue block of the decomposed first layer: once per block, not
     # once per (UE, server) pair
-    ue_h = jnp.dot(ue, w1[:d_ue, :],
-                   preferred_element_type=jnp.float32)  # (bn, 48)
+    ue_h = _dot(ue, w1[:d_ue, :])                      # (bn, 48)
     for e in range(n_srv):
         g0 = geom_ref[e, 0]
         g1 = geom_ref[e, 1]
         g2 = geom_ref[e, 2]
         semb = jnp.tanh(
-            jnp.dot(_srv_row(g0, g1, g2, per_slot, consts), wsrv_ref[...],
-                    preferred_element_type=jnp.float32)
+            _dot(_srv_row(g0, g1, g2, per_slot, consts), wsrv_ref[...])
             + bsrv_ref[...])                            # (1, S)
         srv_ref[e, :] = semb[0]
         dist_c, rate_c, te_c = _edge_cols(d, work, g0, g1, g2, consts)
         edge = jnp.concatenate([dist_c, rate_c, te_c], axis=1)  # (bn, 3)
         h = jnp.tanh(
             ue_h
-            + jnp.dot(semb, w1[d_ue:d_ue + s_dim, :],
-                      preferred_element_type=jnp.float32)
-            + jnp.dot(edge, w1[d_ue + s_dim:, :],
-                      preferred_element_type=jnp.float32)
+            + _dot(semb, w1[d_ue:d_ue + s_dim, :])
+            + _dot(edge, w1[d_ue + s_dim:, :])
             + b1)                                       # (bn, 48)
-        logit = jnp.dot(h, w2_ref[...],
-                        preferred_element_type=jnp.float32)
+        logit = _dot(h, w2_ref[...])
         logits_ref[:, e] = logit[:, 0] + b2_ref[0, 0]
 
 
 def pair_scorer_pallas(ue_emb, d, work, active, geom, consts,
                        w_srv, b_srv, w1, b1, w2, b2, *,
-                       block_n=256, interpret=True):
+                       block_n=256, interpret):
     """Fused pair scorer -> (route_logits (N, E), srv_emb (E, S)).
 
     ue_emb: (N, d_ue) tanh'd UE embeddings; d/work/active: (N,) raw
@@ -149,7 +152,7 @@ def pair_scorer_pallas(ue_emb, d, work, active, geom, consts,
         out_specs=(row(n_srv), full((n_srv, s_dim))),
         out_shape=(jax.ShapeDtypeStruct((n, n_srv), f32),
                    jax.ShapeDtypeStruct((n_srv, s_dim), f32)),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(consts.astype(f32).reshape(1, N_CONSTS), geom.astype(f32),
@@ -196,3 +199,23 @@ def pair_scorer_xla(ue_emb, d, work, active, geom, consts,
                  + b1)                                         # (N, E, 48)
     logits = (h @ w2 + b2)[..., 0]                             # (N, E)
     return logits, srv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def pair_scorer_fused(interpret, *args):
+    """``pair_scorer_pallas`` with a gradient: Mosaic kernels have no
+    autodiff rule, so the backward pass differentiates
+    ``pair_scorer_xla``, the same math. Training with the fused scorer
+    (``MAHPPOConfig.fused_scorer``) takes its gradients through here."""
+    return pair_scorer_pallas(*args, interpret=interpret)
+
+
+def _fused_fwd(interpret, *args):
+    return pair_scorer_pallas(*args, interpret=interpret), args
+
+
+def _fused_bwd(interpret, args, cotangents):
+    return jax.vjp(pair_scorer_xla, *args)[1](cotangents)
+
+
+pair_scorer_fused.defvjp(_fused_fwd, _fused_bwd)

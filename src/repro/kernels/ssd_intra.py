@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -46,7 +45,7 @@ def _kernel(c_ref, b_ref, la_ref, dt_ref, x_ref, o_ref, cb_ref):
                                 ).astype(o_ref.dtype)
 
 
-def ssd_intra(xh, dt, la, Bm, Cm, *, interpret=True):
+def ssd_intra(xh, dt, la, Bm, Cm, *, interpret):
     """xh: (B, NC, Q, H, P); dt, la: (B, NC, Q, H) f32;
     Bm, Cm: (B, NC, Q, N). Returns y_intra (B, NC, Q, H, P) f32."""
     b, nc, q, h, p = xh.shape
@@ -66,7 +65,7 @@ def ssd_intra(xh, dt, la, Bm, Cm, *, interpret=True):
                                lambda bi, ci, hi: (bi, ci, 0, hi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, nc, q, h, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((q, q), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(Cm, Bm, la, dt, xh)

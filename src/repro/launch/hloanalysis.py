@@ -195,11 +195,8 @@ def analyze(text: str):
 
 
 def cost_analysis_dict(compiled):
-    """``compiled.cost_analysis()`` normalized across jax versions (older
-    releases return a one-element list of dicts), numeric entries only."""
+    """``compiled.cost_analysis()``, numeric entries only."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return {k: v for k, v in ca.items() if isinstance(v, (int, float))}
 
 

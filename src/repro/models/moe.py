@@ -125,7 +125,6 @@ def apply_moe_ep(p, x, cfg, mesh):
     psum over 'model' per layer — the same volume as a dense TP layer's
     activation all-reduce.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -188,12 +187,12 @@ def apply_moe_ep(p, x, cfg, mesh):
 
     wspec_i = P("model", "data" if cfg.fsdp else None, None)
     wspec_o = P("model", None, "data" if cfg.fsdp else None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None), wspec_i, wspec_i,
                   wspec_o),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["wi"], p["wg"], p["wo"])
 
     if m.n_shared_experts:
@@ -211,7 +210,6 @@ def apply_moe_ep_decode(p, x, cfg, mesh):
     experts, and partials are psum'd. Collective volume is O(tokens*d), not
     O(params) — the FSDP-gather path costs ~params bytes per step, which at
     one token per sequence is catastrophic (see EXPERIMENTS.md §Perf)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -283,12 +281,12 @@ def apply_moe_ep_decode(p, x, cfg, mesh):
 
     wspec_i = P("model", "data", None)
     wspec_o = P("model", None, "data")
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None), wspec_i, wspec_i,
                   wspec_o),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["wi"], p["wg"], p["wo"])
 
     if m.n_shared_experts:

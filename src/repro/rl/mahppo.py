@@ -43,7 +43,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.env.mecenv import MECEnv
@@ -270,8 +269,8 @@ def make_train_fns(env: MECEnv, cfg: MAHPPOConfig):
     # steps only its local n_envs / n_shards envs; auto-reset is already
     # batched inside env.step (a jnp.where over the done mask), so a
     # sharded step never syncs per-env or cross-shard. The update step
-    # consumes the env-sharded trajectory as-is — GSPMD inserts the
-    # gathers for the fleet-global minibatch draws. Built only when
+    # gathers the env-sharded trajectory for the fleet-global minibatch
+    # draws (see the shard_map around ``update`` below). Built only when
     # cfg.n_shards > 1: the single-device iteration below traces exactly
     # the pre-sharding graph (key stream included).
     if cfg.n_shards > 1:
@@ -282,11 +281,11 @@ def make_train_fns(env: MECEnv, cfg: MAHPPOConfig):
             states, _, traj, last_v = collect(agent, key, states)
             return states, traj, last_v
 
-        collect_sharded = shard_map(
+        collect_sharded = jax.shard_map(
             _collect_local, mesh=mesh,
             in_specs=(P(), P(), P("env")),
             out_specs=(P("env"), P(None, "env"), P("env")),
-            check_rep=False)
+            check_vma=False)
 
     def loss_fn(agent, batch):
         obs, actions = batch["obs"], batch["actions"]
@@ -355,6 +354,13 @@ def make_train_fns(env: MECEnv, cfg: MAHPPOConfig):
         (agent, opt), metrics = jax.lax.scan(epoch_body, (agent, opt), keys)
         metrics = jax.tree_util.tree_map(lambda x: x[-1], metrics)
         return agent, opt, metrics
+
+    if cfg.n_shards > 1:
+        # a Mosaic kernel (the fused scorer on TPU) cannot be partitioned
+        # automatically, so the update runs under shard_map as well: each
+        # device applies the same update to the whole gathered trajectory
+        update = jax.shard_map(update, mesh=mesh, in_specs=P(),
+                               out_specs=P(), check_vma=False)
 
     @jax.jit
     def iteration(agent, opt, key, states):
@@ -499,9 +505,9 @@ def evaluate_policy(env: MECEnv, agent, *, frames=64, seed=0,
                              f"n_shards={n_shards}")
         fn = jax.vmap(rollout)
         if n_shards > 1:
-            fn = shard_map(fn, mesh=_env_mesh(n_shards),
-                           in_specs=(P("env"),), out_specs=P("env"),
-                           check_rep=False)
+            fn = jax.shard_map(fn, mesh=_env_mesh(n_shards),
+                               in_specs=(P("env"),), out_specs=P("env"),
+                               check_vma=False)
         out = jax.jit(fn)(jax.random.split(jax.random.PRNGKey(seed),
                                            n_envs))
     res = {k: float(np.asarray(v).mean()) for k, v in out.items()}

@@ -190,6 +190,28 @@ def test_pair_scorer_unknown_impl_raises():
         ops.pair_scorer(*args, impl="cuda")
 
 
+def test_pair_scorer_pallas_gradient_matches_xla():
+    """Training differentiates the scorer: the Pallas path takes the XLA
+    form's gradient (a Mosaic kernel has no autodiff rule), under vmap
+    too, as the MAHPPO loss applies it over a minibatch."""
+    ue_emb, raw, srv_enc, scorer = _pair_scorer_inputs(
+        jax.random.PRNGKey(5), 40, 3)
+
+    def loss(impl):
+        def f(ue, sc):
+            lg, sv = jax.vmap(lambda u: ops.pair_scorer(
+                u, raw, srv_enc, sc, impl=impl,
+                interpret=True if impl == "pallas" else None))(ue)
+            return jnp.sum(jnp.sin(lg)) + jnp.sum(sv ** 2)
+        return jax.grad(f, argnums=(0, 1))(jnp.stack([ue_emb, -ue_emb]),
+                                           scorer)
+
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
+        loss("pallas"), loss("xla"))
+
+
 # --------------------------------------------- quant impl routing (PR 10)
 # quantize/dequantize grew the same dual-impl REPRO_*_IMPL convention as
 # pair_scorer: decomposed XLA off-TPU, the Pallas kernel on TPU, env-var

@@ -146,8 +146,6 @@ def test_dryrun_single_combo_host_mesh():
     fn = jax.jit(train_step, in_shardings=(psh, None, bsh))
     compiled = fn.lower(params, opt, batch).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):       # older jax: one dict per program
-        ca = ca[0]
     assert ca["flops"] > 0
 
 
