@@ -96,10 +96,13 @@ def run_cell(cell, seed, seconds, trace, *, jax, counter, device,
         window = tr.span_window(info["window_span"])
         if window is None:
             raise harness.BenchError("the trace holds no window span")
-        if device["platform"] == "tpu" and not tr.ops:
-            raise harness.BenchError(
-                f"the trace holds no {trace_lib.OPS_LINE!r} line on a "
-                f"device plane: the per-layer metrics would read nothing")
+        if device["platform"] == "tpu":
+            for line, found in ((trace_lib.OPS_LINE, tr.ops),
+                                (trace_lib.STEPS_LINE, tr.steps)):
+                if not found:
+                    raise harness.BenchError(
+                        f"the trace holds no {line!r} line on a device "
+                        f"plane: the per-layer metrics would read nothing")
         peaks = cell.peaks(device["kind"])
         ctx = MetricContext(cell, info, setup, spans, tr, window, peaks)
         for m in cell.per_layer():

@@ -43,3 +43,31 @@ def test_control_is_not_correct(root, workload, monkeypatch):
     result, checks = run(root, workload, 3, seconds=0.2)
     assert not result["correct"], checks
     assert result["failed"] > 0
+
+
+@pytest.mark.parametrize("line", ["XLA Ops", "XLA Modules"])
+def test_tpu_trace_without_a_device_line_is_an_error(root, line,
+                                                      monkeypatch):
+    """On a TPU, a trace that lacks a device plane's ``XLA Ops`` or
+    ``XLA Modules`` line stops the run, rather than leaving metrics out."""
+    import jax
+
+    from bench import run as bench_run
+    from bench import trace as trace_lib
+    from bench_tiny import CPU_AS_CHIP
+
+    real_load = trace_lib.load
+
+    def load(trace_dir, host_names):
+        host = real_load(trace_dir, host_names).host
+        ops = {} if line == trace_lib.OPS_LINE else {0: [("op", 0, 1)]}
+        steps = {} if line == trace_lib.STEPS_LINE else {0: [("s", 0, 1)]}
+        return trace_lib.Trace(ops, host, steps)
+
+    monkeypatch.setattr(trace_lib, "load", load)
+    cell = harness.Cell(CELLS[0], root=root)
+    with pytest.raises(harness.BenchError, match=line):
+        bench_run.run_cell(
+            cell, 5, 0.3, 1, jax=jax, counter=harness.CompileCounter(jax),
+            device=dict(CPU_AS_CHIP, platform="tpu"), t_start=0.0,
+            devices=jax.devices()[:1])
