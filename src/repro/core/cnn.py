@@ -345,14 +345,15 @@ CNN_FACTORY = {"resnet18": make_resnet18, "vgg11": make_vgg11,
 
 def forward(model: CNNModel, params, x, upto=None):
     """Run modules [0, upto) (None = all). x: (B, 3, H, W)."""
-    n = model.n_modules if upto is None else upto
-    for i in range(n):
-        x = model.run_module(params[i], i, x)
-    return x
+    return forward_from(model, params, x, 0, upto)
 
 
-def forward_from(model: CNNModel, params, feat, start):
+def forward_from(model: CNNModel, params, feat, start, stop=None):
+    """Run modules [start, stop) (None = to the last) on ``feat``. Module
+    ``i`` runs under the named scope ``module<i>``, which the device
+    trace reads as the layer each op belongs to."""
     x = feat
-    for i in range(start, model.n_modules):
-        x = model.run_module(params[i], i, x)
+    for i in range(start, model.n_modules if stop is None else stop):
+        with jax.named_scope(f"module{i}"):
+            x = model.run_module(params[i], i, x)
     return x

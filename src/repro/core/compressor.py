@@ -81,9 +81,12 @@ def encode(ae, feat):
 
 
 def decode(ae, z):
-    if z.ndim == 4:
-        return jnp.einsum("bdhw,dc->bchw", z, ae["dec"])
-    return z @ ae["dec"]
+    """z: (B, D, H, W) or (B, S, D) -> features, under the named scope
+    ``ae_decode``."""
+    with jax.named_scope("ae_decode"):
+        if z.ndim == 4:
+            return jnp.einsum("bdhw,dc->bchw", z, ae["dec"])
+        return z @ ae["dec"]
 
 
 def roundtrip(ae, feat, bits=None):

@@ -52,20 +52,24 @@ def quantize(x, mn, mx, *, bits=8, impl=None, interpret=None):
 
 def dequantize(y, mn, mx, *, bits=8, out_dtype=jnp.float32, impl=None,
                interpret=None):
-    """Inverse of :func:`quantize`; same impl selection (REPRO_QUANT_IMPL)."""
+    """Inverse of :func:`quantize`; same impl selection (REPRO_QUANT_IMPL).
+    Either impl runs under the named scope ``dequantize``, so the device
+    trace finds the same work whatever implements it."""
     if impl is None:
         impl = "pallas" if interpret is not None \
             else _impl_default("REPRO_QUANT_IMPL")
-    if impl == "xla":
-        return _q.dequantize_xla(y, mn, mx, bits=bits, out_dtype=out_dtype)
-    if impl != "pallas":
-        raise ValueError(f"unknown quant impl {impl!r}")
-    interpret = _interpret_default() if interpret is None else interpret
-    shape = y.shape
-    y2 = y.reshape(-1, shape[-1])
-    out = _q.dequantize_2d(y2, mn, mx, bits=bits, out_dtype=out_dtype,
-                           interpret=interpret)
-    return out.reshape(shape)
+    with jax.named_scope("dequantize"):
+        if impl == "xla":
+            return _q.dequantize_xla(y, mn, mx, bits=bits,
+                                     out_dtype=out_dtype)
+        if impl != "pallas":
+            raise ValueError(f"unknown quant impl {impl!r}")
+        interpret = _interpret_default() if interpret is None else interpret
+        shape = y.shape
+        y2 = y.reshape(-1, shape[-1])
+        out = _q.dequantize_2d(y2, mn, mx, bits=bits, out_dtype=out_dtype,
+                               interpret=interpret)
+        return out.reshape(shape)
 
 
 def bottleneck_encode(x, w, mn, mx, *, bits=8, interpret=None):

@@ -4,11 +4,15 @@ This is the only test file that compiles for the chip. Nothing runs, so no
 accelerator is needed, but the TPU compiler refuses here what interpret mode
 accepts: an unsupported cast, a misaligned tile, too much VMEM. Each test
 lowers one kernel through ``kernels.ops`` at a main-path width and asserts
-that the program holds the Mosaic kernel (``tpu_custom_call``).
+that the program holds the Mosaic kernel (``tpu_custom_call``). The last
+compiles the benchmark's edge step and checks that its layers keep their
+named scopes.
 
 The topology is described inside a fixture, never at import: one process at
 a time may load the TPU library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -91,3 +95,44 @@ def test_bottleneck_encode_compiles(one_chip):
         x, w, a, b, interpret=False),
         _spec(one_chip, (4096, 2048)), _spec(one_chip, (2048, 512)),
         _spec(one_chip, ()), _spec(one_chip, ()))
+
+
+@pytest.mark.parametrize("arch", ["resnet18", "mobilenetv2"])
+def test_edge_step_layers_keep_their_scopes(one_chip, arch):
+    """The benchmark's edge step at its cells' shapes (224x224, batch 32,
+    codes at 1/16 of the channels after the first split point): the
+    dequantize kernel carries ``dequantize``, and every fusion that holds
+    a convolution carries one program scope, a ``module<k>`` or the
+    decode's ``ae_decode``, the modules after the split all among them."""
+    from test_edge_scopes import edge_step, scopes
+
+    fn, args, model, _ = edge_step(
+        arch, impl="pallas", interpret=False, width=1.0, size=224,
+        batch=32, classes=101, ratio=16, sharding=one_chip)
+    text = fn.lower(*args).compile().as_text()
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            bodies[name.lstrip("%")] = []
+        elif name is not None:
+            bodies[name.lstrip("%")].append(line)
+    entry = next(b for n, b in bodies.items() if f"ENTRY %{n} " in text)
+    with_conv = {n for n, b in bodies.items()
+                 if any(" convolution(" in l for l in b)}
+
+    def op_scopes(line):
+        m = re.search(r'op_name="([^"]*)"', line)
+        return scopes(m.group(1)) if m else []
+
+    kernel = [op_scopes(l) for l in entry if "tpu_custom_call" in l]
+    assert kernel == [["dequantize"]]
+    def called(line):
+        m = re.search(r"calls=%([\w.-]+)", line)
+        return m and m.group(1)
+
+    conv = [op_scopes(l) for l in entry if called(l) in with_conv]
+    assert conv and all(len(s) == 1 for s in conv), conv
+    start = model.split_after[0] + 1
+    assert {f"module{i}" for i in range(start, model.n_modules)} <= {
+        s[0] for s in conv}
