@@ -5,7 +5,12 @@ of the paper measures these on a Jetson Nano; we derive them from the same
 module granularity — see core/overhead.py).
 
 BatchNorm uses batch statistics (train-mode) throughout; running-stat
-bookkeeping is irrelevant to the compression/scheduling experiments.
+bookkeeping is irrelevant to the compression/scheduling experiments. The
+statistics come in one pass (``_bn_stats``): a per-channel sum and sum of
+squares in float32, variance E[x^2] - mean^2. Both sums fuse into the
+convolution that makes the activation; the two-pass mean((x - mean)^2)
+needs the mean first, so XLA gives it a second full read of every
+activation.
 """
 from __future__ import annotations
 
@@ -39,9 +44,18 @@ def _bn_init(ch):
     return {"scale": jnp.ones((ch,)), "bias": jnp.zeros((ch,))}
 
 
+def _bn_stats(x):
+    """Per-channel batch mean and biased variance of NCHW ``x`` from one
+    sum and one sum of squares; the variance is clamped at 0 against
+    rounding."""
+    n = x.shape[0] * x.shape[2] * x.shape[3]
+    mu = x.sum(axis=(0, 2, 3), keepdims=True) / n
+    ms = jnp.square(x).sum(axis=(0, 2, 3), keepdims=True) / n
+    return mu, jnp.maximum(ms - jnp.square(mu), 0.0)
+
+
 def _bn(p, x, eps=1e-5):
-    mu = x.mean(axis=(0, 2, 3), keepdims=True)
-    var = x.var(axis=(0, 2, 3), keepdims=True)
+    mu, var = _bn_stats(x)
     xn = (x - mu) * jax.lax.rsqrt(var + eps)
     return xn * p["scale"][None, :, None, None] + p["bias"][None, :, None, None]
 

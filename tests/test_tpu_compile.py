@@ -5,12 +5,13 @@ accelerator is needed, but the TPU compiler refuses here what interpret mode
 accepts: an unsupported cast, a misaligned tile, too much VMEM. Each test
 lowers one kernel through ``kernels.ops`` at a main-path width and asserts
 that the program holds the Mosaic kernel (``tpu_custom_call``). The last
-compiles the benchmark's edge step and checks that its layers keep their
-named scopes.
+two compile the benchmark's edge step and check that its layers keep their
+named scopes, and that BatchNorm's statistics fuse into the convolutions.
 
 The topology is described inside a fixture, never at import: one process at
 a time may load the TPU library, and every test worker imports this file.
 """
+import functools
 import re
 
 import jax
@@ -97,18 +98,16 @@ def test_bottleneck_encode_compiles(one_chip):
         _spec(one_chip, ()), _spec(one_chip, ()))
 
 
-@pytest.mark.parametrize("arch", ["resnet18", "mobilenetv2"])
-def test_edge_step_layers_keep_their_scopes(one_chip, arch):
-    """The benchmark's edge step at its cells' shapes (224x224, batch 32,
-    codes at 1/16 of the channels after the first split point): the
-    dequantize kernel carries ``dequantize``, and every fusion that holds
-    a convolution carries one program scope, a ``module<k>`` or the
-    decode's ``ae_decode``, the modules after the split all among them."""
-    from test_edge_scopes import edge_step, scopes
+@functools.cache
+def _edge_step_hlo(arch, sharding):
+    """(compiled HLO text, its computations {name: body lines}, the entry's
+    body, model) of the benchmark's edge step at its cells' shapes (224x224,
+    batch 32, codes at 1/16 of the channels after the first split point)."""
+    from test_edge_scopes import edge_step
 
     fn, args, model, _ = edge_step(
         arch, impl="pallas", interpret=False, width=1.0, size=224,
-        batch=32, classes=101, ratio=16, sharding=one_chip)
+        batch=32, classes=101, ratio=16, sharding=sharding)
     text = fn.lower(*args).compile().as_text()
     bodies, name = {}, None
     for line in text.splitlines():
@@ -118,6 +117,23 @@ def test_edge_step_layers_keep_their_scopes(one_chip, arch):
         elif name is not None:
             bodies[name.lstrip("%")].append(line)
     entry = next(b for n, b in bodies.items() if f"ENTRY %{n} " in text)
+    return text, bodies, entry, model
+
+
+def _called(line):
+    m = re.search(r"calls=%([\w.-]+)", line)
+    return m and m.group(1)
+
+
+@pytest.mark.parametrize("arch", ["resnet18", "mobilenetv2"])
+def test_edge_step_layers_keep_their_scopes(one_chip, arch):
+    """The benchmark's edge step at its cells' shapes: the dequantize
+    kernel carries ``dequantize``, and every fusion that holds a
+    convolution carries one program scope, a ``module<k>`` or the
+    decode's ``ae_decode``, the modules after the split all among them."""
+    from test_edge_scopes import scopes
+
+    _, bodies, entry, model = _edge_step_hlo(arch, one_chip)
     with_conv = {n for n, b in bodies.items()
                  if any(" convolution(" in l for l in b)}
 
@@ -127,12 +143,31 @@ def test_edge_step_layers_keep_their_scopes(one_chip, arch):
 
     kernel = [op_scopes(l) for l in entry if "tpu_custom_call" in l]
     assert kernel == [["dequantize"]]
-    def called(line):
-        m = re.search(r"calls=%([\w.-]+)", line)
-        return m and m.group(1)
-
-    conv = [op_scopes(l) for l in entry if called(l) in with_conv]
+    conv = [op_scopes(l) for l in entry if _called(l) in with_conv]
     assert conv and all(len(s) == 1 for s in conv), conv
     start = model.split_after[0] + 1
     assert {f"module{i}" for i in range(start, model.n_modules)} <= {
         s[0] for s in conv}
+
+
+# an activation of the edge step: batch 32, NCHW
+ACTIVATION = re.compile(r"f32\[32,\d+,\d+,\d+\]\S* parameter\(")
+VECTORS = re.compile(r"\(?f32\[\d+\]\{[^}]*\}(, f32\[\d+\]\{[^}]*\})*\)?")
+
+
+@pytest.mark.parametrize("arch", ["resnet18", "mobilenetv2"])
+def test_edge_step_bn_stats_fuse_into_producers(one_chip, arch):
+    """BatchNorm's statistics take one pass: no fusion of the compiled edge
+    step reads an activation only to return per-channel vectors, as the
+    variance pass of the two-pass form ``mean((x - mean)^2)`` did (XLA
+    names those ``multiply_reduce_fusion``). Both sums come out of the
+    fusion that makes the activation."""
+    text, bodies, entry, _ = _edge_step_hlo(arch, one_chip)
+    reduce_only = []
+    for line in entry:
+        m = re.match(r"\s*%(\S+) = (.*?) fusion\(", line)
+        if (m and VECTORS.fullmatch(m.group(2))
+                and any(ACTIVATION.search(l) for l in bodies[_called(line)])):
+            reduce_only.append(m.group(1))
+    assert reduce_only == []
+    assert "multiply_reduce_fusion" not in text
