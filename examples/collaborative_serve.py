@@ -86,8 +86,9 @@ from repro.models.model import _logits, _run_stack, layer_plan
 
 def run_split_forward(params, cfg, tokens, split_layer, ae, bits=8):
     """UE part -> compress -> (channel) -> decompress -> edge part."""
-    pattern, n_groups, tail_types = layer_plan(cfg)
-    assert len(pattern) == 1, "example uses uniform-pattern archs"
+    lead, pattern, n_groups, tail_types = layer_plan(cfg)
+    assert len(pattern) == 1 and not lead, \
+        "example uses uniform-pattern archs"
     bt = pattern[0]
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))

@@ -17,9 +17,10 @@ _MODULES = {
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "mamba2-1.3b": "mamba2_1_3b",
     "llama-3.2-vision-90b": "llama_3_2_vision_90b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
-# the 10 assigned architectures (drives --all sweeps and smoke tests)
+# the architectures every --all sweep and smoke test runs
 ARCH_IDS = tuple(_MODULES)
 
 # extra variants (selectable via --arch, excluded from ARCH_IDS)

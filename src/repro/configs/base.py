@@ -21,6 +21,14 @@ class MoEConfig:
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # DeepSeek-V3's layer: the ``noaux_tc`` router with one group (sigmoid
+    # scores, a per-expert correction bias that chooses the experts but
+    # does not weight them, top-k weights normalised and scaled by
+    # ``routed_scaling``), and every token reaches all top_k experts (sort
+    # + grouped matmul). False is the GShard layer: softmax router and a
+    # capacity dispatch that drops tokens past ``capacity_factor``.
+    dropless: bool = False
+    routed_scaling: float = 1.0        # dropless only
 
 
 @dataclass(frozen=True)
@@ -60,6 +68,20 @@ class ModelConfig:
     rope_theta: float = 10000.0
     rope_fraction: float = 1.0
     tie_embeddings: bool = False
+    norm_eps: float = 1e-6
+    # Multi-head latent attention (DeepSeek-V2/V3): kv_lora_rank > 0 turns
+    # it on. Keys and values come from a per-token latent c_kv of
+    # kv_lora_rank values (RMS-normed) plus one shared RoPE key of
+    # qk_rope_head_dim; the cache holds those two. q_lora_rank > 0 adds
+    # the query's own low-rank path.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading dense layers ("dense" blocks of width d_ff) before the
+    # block_pattern starts (DeepSeek's first_k_dense_replace)
+    first_dense_layers: int = 0
     # Block layout. Entries: "dense" (attn+mlp), "moe" (attn+moe),
     # "mamba2", "rec" (RG-LRU+mlp), "lattn" (local attn+mlp),
     # "xattn" (cross-attn+mlp, VLM), "decx" (self+cross, enc-dec decoder).
@@ -98,10 +120,16 @@ class ModelConfig:
     def pattern_len(self) -> int:
         return len(self.block_pattern)
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
     def block_types(self) -> Tuple[str, ...]:
-        """Block type of each of the n_layers layers."""
-        p = self.block_pattern
-        return tuple(p[i % len(p)] for i in range(self.n_layers))
+        """Block type of each of the n_layers layers: the leading dense
+        layers, then block_pattern repeated cyclically."""
+        p, k = self.block_pattern, self.first_dense_layers
+        return ("dense",) * k + tuple(
+            p[i % len(p)] for i in range(self.n_layers - k))
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -147,6 +175,9 @@ def reduced(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 256,
             cfg.moe, n_experts=4, top_k=2, d_expert=d_model // 2,
             n_shared_experts=min(cfg.moe.n_shared_experts, 1),
             capacity_factor=4.0)
+    if cfg.mla:
+        kw.update(kv_lora_rank=32, qk_nope_head_dim=32, qk_rope_head_dim=16,
+                  v_head_dim=32, q_lora_rank=48 if cfg.q_lora_rank else 0)
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(
             cfg.ssm, d_state=16, head_dim=32, chunk=16)
@@ -155,5 +186,7 @@ def reduced(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 256,
     if cfg.n_aux_tokens:
         kw["n_aux_tokens"] = 16
     # keep the pattern but make sure n_layers covers it
-    kw["n_layers"] = max(n_layers, len(cfg.block_pattern))
+    kw["first_dense_layers"] = min(cfg.first_dense_layers, 1)
+    kw["n_layers"] = max(n_layers, kw["first_dense_layers"]
+                         + len(cfg.block_pattern))
     return cfg.replace(**kw)

@@ -131,6 +131,28 @@ def task_latency_energy(l_b, n_b, rate, p_compute, p_tx, t_edge=None):
 
 
 # -------------------------------------------------- transformer layer costs
+def mla_costs(cfg: ModelConfig, s: int, ctx: int, *, decode=False):
+    """(flops, param elements) of one MLA layer's attention for ``s``
+    query tokens against ``ctx`` keys. Prefill expands keys and values
+    per head; decode (``decode``) attends in the latent space, the key
+    up-projection absorbed into the query and the value one applied
+    after, as ``models.attention.mla_decode`` does."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    if cfg.q_lora_rank:
+        q_par = d * cfg.q_lora_rank + cfg.q_lora_rank * h * (dn + dr)
+    else:
+        q_par = d * h * (dn + dr)
+    par = q_par + d * (r + dr) + r * h * (dn + dv) + h * dv * d
+    if decode:
+        fl = 2 * s * (q_par + d * (r + dr) + h * dn * r + h * r * dv
+                      + h * dv * d)
+        fl += 2 * s * ctx * h * (2 * r + dr)
+    else:
+        fl = 2 * s * par + 2 * s * ctx * h * (dn + dr + dv)
+    return fl, par
+
+
 def layer_costs(cfg: ModelConfig, seq_len: int) -> List[dict]:
     """Per-layer {flops, bytes, param_bytes} for a seq_len-token forward.
     bytes = params read once + activations in/out (bf16)."""
@@ -166,6 +188,9 @@ def layer_costs(cfg: ModelConfig, seq_len: int) -> List[dict]:
         attn_qk = 4 * s * ctx * hq * dh
         a_params = (d * (hq + 2 * hkv) * dh + hq * dh * d) * 2
         fl = attn_proj + attn_qk
+        if cfg.mla and bt in ("dense", "moe"):
+            fl, a_params = mla_costs(cfg, s, ctx)
+            a_params *= 2
         pbytes = a_params
         if bt in ("xattn",):
             fl = 2 * s * d * hq * dh + 2 * s * hq * dh * d \
@@ -240,6 +265,11 @@ def decode_layer_costs(cfg: ModelConfig, ctx_len: int) -> List[dict]:
         cache_b = 2 * ctx * hkv * dh * kv_el \
             + (2 * ctx * hkv * 4 if cfg.kv_quant_bits else 0)
         fl = attn_proj + attn_qk
+        if cfg.mla and bt in ("dense", "moe"):
+            fl, a_params = mla_costs(cfg, 1, ctx, decode=True)
+            a_params *= 2
+            cache_b = ctx * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) \
+                * (4 if cfg.compute_dtype == "float32" else 2)
         pbytes = a_params
         if bt == "xattn":
             fl = 2 * d * hq * dh + 2 * hq * dh * d \

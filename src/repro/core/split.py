@@ -392,7 +392,8 @@ def llm_decode_split_table(cfg: ModelConfig, ctx_len: int, *,
       b = B+1  full local: prefill + gen_tokens decode steps on the UE
 
     ``kv_bits`` overrides cfg.kv_quant_bits for the SHIPPED cache (0 =
-    16-bit). Opt-in like the other measured builders; the default
+    16-bit; an MLA layer ships its latent unquantized). Opt-in like the
+    other measured builders; the default
     ``transformer_split_table`` is untouched."""
     from repro.models.cache import entry_payload_bits
 

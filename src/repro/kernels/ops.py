@@ -72,6 +72,17 @@ def dequantize(y, mn, mx, *, bits=8, out_dtype=jnp.float32, impl=None,
         return out.reshape(shape)
 
 
+def grouped_matmul(lhs, rhs, group_sizes):
+    """Rows of ``lhs`` (m, k), sorted into consecutive groups of
+    ``group_sizes`` (g,), each times its group's ``rhs`` (g, k, n), with
+    float32 accumulation: (m, n) float32. Rows past the groups' sum come
+    out zero. XLA's ``ragged_dot``: on a v5e at the Moonlight cell's
+    shapes (98,304 rows, 64 groups) it ran 4-6x faster than the megablox
+    Pallas ``gmm`` at that kernel's default tiling."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
 def bottleneck_encode(x, w, mn, mx, *, bits=8, interpret=None):
     """Fused compressor encode. x: (..., d); w: (d, d')."""
     interpret = _interpret_default() if interpret is None else interpret

@@ -34,6 +34,18 @@ def flat_trunk_ref(x, codes, mns, mxs, bs, bits=8):
     return h
 
 
+def grouped_matmul_ref(lhs, rhs, group_sizes):
+    """Each row of ``lhs`` times the ``rhs`` of the group it falls in
+    (groups are consecutive runs of ``group_sizes`` rows), float32."""
+    ends = jnp.cumsum(group_sizes)
+    group = jnp.searchsorted(ends, jnp.arange(lhs.shape[0]), side="right")
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32)
+    for gi in range(rhs.shape[0]):
+        y = jnp.dot(lhs.astype(jnp.float32), rhs[gi].astype(jnp.float32))
+        out = jnp.where((group == gi)[:, None], y, out)
+    return out
+
+
 def bottleneck_encode_ref(x, w, mn, mx, bits=8):
     """Fused compressor encode: (T, d) @ (d, d') then quantize."""
     z = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32))

@@ -12,7 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import (apply_rope, dense_init, dtype_of,
+from repro.models.layers import (apply_rope, dense_init, dtype_of, mm,
                                  rms_head_norm)
 
 NEG_INF = -1e30
@@ -66,10 +66,10 @@ def flash_attention(q, k, v, *, q_positions, k_positions, causal=True,
     queries) blocked over the query axis so peak memory is
     O(q_block * chunk) per head rather than O(Sq * Sk).
 
-    q: (B, Sq, Hq, D);  k, v: (B, Sk, Hkv, D);  Hq % Hkv == 0.
+    q, k: (B, Sq|Sk, Hq|Hkv, D);  v: (B, Sk, Hkv, Dv);  Hq % Hkv == 0.
     q_positions: (B, Sq) int32;  k_positions: (B, Sk) int32, -1 = invalid slot.
     window > 0 limits attention to k_pos in (q_pos - window, q_pos].
-    Returns (B, Sq, Hq, D) in q.dtype.
+    Returns (B, Sq, Hq, Dv) in q.dtype.
     """
     b, sq, hq, dh = q.shape
     if sq > q_block:
@@ -84,7 +84,8 @@ def flash_attention(q, k, v, *, q_positions, k_positions, causal=True,
                                     k_positions=k_positions, causal=causal,
                                     window=window, chunk=chunk),
             (qp, pp))
-        out = out.transpose(1, 0, 2, 3, 4).reshape(b, nq * q_block, hq, dh)
+        out = out.transpose(1, 0, 2, 3, 4).reshape(
+            b, nq * q_block, hq, v.shape[-1])
         return out[:, :sq]
     if sq == 1:
         # decode: no sequential dependency — per-chunk partials in parallel,
@@ -181,7 +182,7 @@ def _flash_inner(q, k, v, *, q_positions, k_positions, causal, window, chunk):
     # casting up front materializes an f32 copy of the whole cache (15 GB for
     # a 32k GQA cache), which GSPMD then shards+all-gathers. See §Perf log.
     kc = k.reshape(b, n_chunks, chunk, hkv, dh)
-    vc = v.reshape(b, n_chunks, chunk, hkv, dh)
+    vc = v.reshape(b, n_chunks, chunk, hkv, v.shape[-1])
     pc = k_positions.reshape(b, n_chunks, chunk)
 
     def step(carry, xs):
@@ -205,15 +206,16 @@ def _flash_inner(q, k, v, *, q_positions, k_positions, causal, window, chunk):
             preferred_element_type=jnp.float32)
         return (m_new, l_new, acc_new), None
 
+    dv = v.shape[-1]
     m0 = jnp.full((b, hkv, g, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, sq), jnp.float32)
-    a0 = jnp.zeros((b, hkv, g, sq, dh), jnp.float32)
+    a0 = jnp.zeros((b, hkv, g, sq, dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, a0),
         (kc.transpose(1, 0, 2, 3, 4), vc.transpose(1, 0, 2, 3, 4),
          pc.transpose(1, 0, 2)))
-    out = acc / jnp.maximum(l, 1e-20)[..., None]          # (B,Hkv,G,Sq,D)
-    out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, dh)
+    out = acc / jnp.maximum(l, 1e-20)[..., None]          # (B,Hkv,G,Sq,Dv)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, dv)
     return out.astype(q.dtype)
 
 
@@ -291,3 +293,105 @@ def cross_attention(p, x, cfg, *, kv=None, context=None):
 def project_cross_kv(p, context, cfg):
     _, k, v = _qkv(p, context, context, cfg)
     return k, v
+
+
+# ------------------------------------------------ multi-head latent attention
+def init_mla(key, cfg):
+    """DeepSeek-V2/V3 attention weights: the query (directly, or through
+    its own low-rank path with ``q_lora_rank``), the joint down-projection
+    to the latent ``c_kv`` and the shared RoPE key, the latent's RMSNorm,
+    its up-projection to per-head keys and values, and the output."""
+    d, h = cfg.d_model, cfg.n_heads
+    dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim, cfg.kv_lora_rank)
+    dt = dtype_of(cfg.param_dtype)
+    ks = jax.random.split(key, 5)
+    p = {"wkv_a": dense_init(ks[1], (d, r + dr), dt),
+         "kv_norm": jnp.ones((r,), dt),
+         "wkv_b": dense_init(ks[2], (r, h * (dn + dv)), dt),
+         "wo": dense_init(ks[3], (h * dv, d), dt)}
+    if cfg.q_lora_rank:
+        p["wq_a"] = dense_init(ks[0], (d, cfg.q_lora_rank), dt)
+        p["q_norm"] = jnp.ones((cfg.q_lora_rank,), dt)
+        p["wq_b"] = dense_init(ks[4], (cfg.q_lora_rank, h * (dn + dr)), dt)
+    else:
+        p["wq"] = dense_init(ks[0], (d, h * (dn + dr)), dt)
+    return p
+
+
+def mla_project(p, x, cfg, positions):
+    """(q_nope (B,S,H,dn), q_rope (B,S,H,dr), c_kv (B,S,r), k_rope
+    (B,S,dr)) of ``x``, float32, RoPE applied. ``c_kv`` is RMS-normed
+    (the ``kv_a_layernorm``); it and ``k_rope`` are what the cache
+    holds. RoPE rotates adjacent pairs of dimensions (``apply_rope``);
+    the published code de-interleaves them first and rotates halves, a
+    permutation of q and k alike that leaves the scores unchanged."""
+    b, s, _ = x.shape
+    h, dn, r = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    eps = cfg.norm_eps
+    if cfg.q_lora_rank:
+        q = mm(rms_head_norm(p["q_norm"], mm(x, p["wq_a"]), eps),
+               p["wq_b"])
+    else:
+        q = mm(x, p["wq"])
+    q = q.reshape(b, s, h, -1)
+    kva = mm(x, p["wkv_a"])
+    c_kv = rms_head_norm(p["kv_norm"], kva[..., :r], eps)
+    q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    k_rope = apply_rope(kva[:, :, None, r:], positions, cfg.rope_theta)
+    return q[..., :dn], q_rope, c_kv, k_rope[:, :, 0]
+
+
+def mla_attention(p, x, cfg, positions):
+    """Causal MLA over the whole sequence (train / prefill). Keys and
+    values are expanded from the latent per head; the one RoPE key is
+    shared by every head. Products take the weights' dtype and accumulate
+    in float32; scores are scaled by 1/sqrt(dn + dr). Returns (out
+    (B,S,d) in x.dtype, (c_kv, k_rope)) for the cache. Runs under the
+    named scope ``mla``."""
+    b, s, _ = x.shape
+    h, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    wdt = p["wkv_b"].dtype
+    with jax.named_scope("mla"):
+        q_nope, q_rope, c_kv, k_rope = mla_project(p, x, cfg, positions)
+        kv = mm(c_kv, p["wkv_b"]).reshape(b, s, h, dn + dv)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(wdt)
+        k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :],
+                                    (b, s, h, k_rope.shape[-1]))
+        k = jnp.concatenate([kv[..., :dn], k_rope_h], axis=-1).astype(wdt)
+        out = flash_attention(q, k, kv[..., dn:].astype(wdt),
+                              q_positions=positions, k_positions=positions,
+                              causal=True, chunk=cfg.attn_chunk)
+        out = mm(out.reshape(b, s, h * dv), p["wo"]).astype(x.dtype)
+    return out, (c_kv, k_rope)
+
+
+def mla_decode(p, x, cfg, positions, cache, slot):
+    """One new token against the latent cache {"ckv", "krope", "pos"}
+    (ring buffers of length L; ``slot`` already modulo L): the token's
+    latent is written at ``slot``, then attention runs in the latent
+    space, the key up-projection absorbed into the query and the value
+    up-projection applied after. Returns (out, new cache entry)."""
+    b, s, _ = x.shape
+    h, dn, dv, r = (cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim,
+                    cfg.kv_lora_rank)
+    q_nope, q_rope, c_kv, k_rope = mla_project(p, x, cfg, positions)
+    pos = jax.lax.dynamic_update_slice(
+        cache["pos"], positions.astype(jnp.int32), (0, slot))
+    ckv = jax.lax.dynamic_update_slice(
+        cache["ckv"], c_kv.astype(cache["ckv"].dtype), (0, slot, 0))
+    krope = jax.lax.dynamic_update_slice(
+        cache["krope"], k_rope.astype(cache["krope"].dtype), (0, slot, 0))
+    w = p["wkv_b"].astype(jnp.float32).reshape(r, h, dn + dv)
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w[..., :dn])
+    scores = (jnp.einsum("bshr,blr->bhsl", q_lat, ckv.astype(jnp.float32))
+              + jnp.einsum("bshd,bld->bhsl", q_rope,
+                           krope.astype(jnp.float32)))
+    scores = scores * (dn + cfg.qk_rope_head_dim) ** -0.5
+    valid = (pos[:, None, None, :] >= 0) & (
+        pos[:, None, None, :] <= positions[:, None, :, None])
+    probs = jax.nn.softmax(jnp.where(valid, scores, NEG_INF), axis=-1)
+    o_lat = jnp.einsum("bhsl,blr->bshr", probs, ckv.astype(jnp.float32))
+    out = jnp.einsum("bshr,rhv->bshv", o_lat, w[..., dn:])
+    out = mm(out.reshape(b, s, h * dv), p["wo"]).astype(x.dtype)
+    return out, {"ckv": ckv, "krope": krope, "pos": pos}

@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn_lib
-from repro.models.layers import apply_mlp, apply_norm, init_mlp, init_norm
+from repro.models.layers import (apply_mlp, apply_norm, dtype_of, init_mlp,
+                                 init_norm)
 from repro.models.moe import apply_moe, init_moe
 from repro.models.rglru import apply_rglru, decode_rglru, init_rglru
 from repro.models.ssm import apply_mamba, decode_mamba, init_mamba
@@ -27,6 +28,11 @@ def init_block(key, cfg, btype):
     if btype == "rec":
         return {"ln1": init_norm(cfg), "mixer": init_rglru(ks[0], cfg),
                 "ln2": init_norm(cfg), "mlp": init_mlp(ks[1], cfg)}
+    if cfg.mla and btype in ("dense", "moe"):
+        ffn = ({"moe": init_moe(ks[1], cfg)} if btype == "moe"
+               else {"mlp": init_mlp(ks[1], cfg)})
+        return {"ln1": init_norm(cfg), "attn": attn_lib.init_mla(ks[0], cfg),
+                "ln2": init_norm(cfg), **ffn}
     if btype in ("dense", "lattn", "enc"):
         return {"ln1": init_norm(cfg), "attn": attn_lib.init_attn(ks[0], cfg),
                 "ln2": init_norm(cfg), "mlp": init_mlp(ks[1], cfg)}
@@ -65,7 +71,8 @@ def apply_block(p, x, cfg, btype, *, positions, mode, context=None,
     "decode" (consume+update cache entry).
     Returns (x, cache_entry, aux_loss); cache_entry is () in train mode.
     """
-    from repro.models.cache import pack_full_kv  # local import (cycle-free)
+    from repro.models.cache import (pack_full_kv,  # local import (cycle-free)
+                                    pack_latent)
 
     aux = 0.0
     window = cfg.window if btype == "lattn" else 0
@@ -99,6 +106,22 @@ def apply_block(p, x, cfg, btype, *, positions, mode, context=None,
             out, (ck, cv) = attn_lib.cross_attention(
                 p["xattn"], h, cfg, context=context)
             entry = () if mode == "train" else {"ck": ck, "cv": cv}
+        x = x + out
+        d, aux = _ffn(p, x, cfg)
+        return x + d, entry, aux
+
+    if cfg.mla and btype in ("dense", "moe"):
+        h = apply_norm(p["ln1"], x, cfg)
+        if mode == "decode":
+            slot = jax.lax.rem(idx, cache["ckv"].shape[1])
+            out, entry = attn_lib.mla_decode(p["attn"], h, cfg, positions,
+                                             cache, slot)
+        else:
+            out, (c_kv, k_rope) = attn_lib.mla_attention(p["attn"], h, cfg,
+                                                         positions)
+            entry = () if mode == "train" else pack_latent(
+                c_kv, k_rope, positions, attn_len,
+                dtype_of(cfg.compute_dtype))
         x = x + out
         d, aux = _ffn(p, x, cfg)
         return x + d, entry, aux

@@ -74,6 +74,19 @@ def pack_full_kv(k, v, positions, cache_len, window=0, kv_bits=0):
     return entry
 
 
+def pack_latent(c_kv, k_rope, positions, cache_len, dtype):
+    """An MLA layer's cache entry from prefill: the latent ``c_kv`` (B, S,
+    r) and the shared RoPE key ``k_rope`` (B, S, dr) in ``dtype``, in
+    slots [0, S) of buffers of ``cache_len`` (>= S), with ``pos``."""
+    b, s = positions.shape
+    pad = ((0, 0), (0, cache_len - s), (0, 0))
+    return {"ckv": jnp.pad(c_kv.astype(dtype), pad),
+            "krope": jnp.pad(k_rope.astype(dtype), pad),
+            "pos": jnp.pad(positions.astype(jnp.int32),
+                           ((0, 0), (0, cache_len - s)),
+                           constant_values=-1)}
+
+
 def entry_shape(cfg, btype, batch, attn_len):
     """Shape/dtype tree (as (shape, dtype) leaves) of one layer's cache."""
     cdt = dtype_of(cfg.compute_dtype)
@@ -90,6 +103,12 @@ def entry_shape(cfg, btype, batch, attn_len):
     if btype == "xattn":
         return {"ck": ((batch, cfg.n_aux_tokens, hkv, dh), cdt),
                 "cv": ((batch, cfg.n_aux_tokens, hkv, dh), cdt)}
+    if cfg.mla and btype in ("dense", "moe"):
+        # the latent and the shared RoPE key: kv_lora_rank +
+        # qk_rope_head_dim values a token, for all heads at once
+        return {"ckv": ((batch, attn_len, cfg.kv_lora_rank), cdt),
+                "krope": ((batch, attn_len, cfg.qk_rope_head_dim), cdt),
+                "pos": ((batch, attn_len), jnp.int32)}
     lc = cfg.window if btype == "lattn" else attn_len
     kv_dt = jnp.int8 if cfg.kv_quant_bits else cdt
     e = {"k": ((batch, lc, hkv, dh), kv_dt),
@@ -110,7 +129,8 @@ def entry_payload_bits(cfg, btype, batch, ctx_len):
     context: ``entry_shape``'s leaves with sequence axes at the FILLED
     length (min(ctx_len, window) for sliding-window layers — the ring
     buffer never holds more), honoring ``kv_quant_bits`` (int8 codes +
-    f32 per-(slot, head) scales). SSM/RG-LRU layers carry O(1) state.
+    f32 per-(slot, head) scales; an MLA layer's latent is never
+    quantized). SSM/RG-LRU layers carry O(1) state.
     The boundary payload of an LLM-decode split
     (core.split.llm_decode_split_table) sums this over the UE-side
     layers, which is what makes f_bits a function of context length."""
@@ -151,7 +171,8 @@ def make_cache(cfg, batch, attn_len, leaf_fn=None):
             return default_leaf(shape, dtype, name == "pos")
         return {name: mk(name, sd) for name, sd in tree.items()}
 
-    pattern, n_groups, tail_types = layer_plan(cfg)
+    lead_types, pattern, n_groups, tail_types = layer_plan(cfg)
     blocks = [build(bt, n_groups) for bt in pattern] if n_groups else []
-    tail = [build(bt) for bt in tail_types]
-    return {"blocks": tuple(blocks), "tail": tuple(tail)}
+    return {"lead": tuple(build(bt) for bt in lead_types),
+            "blocks": tuple(blocks),
+            "tail": tuple(build(bt) for bt in tail_types)}

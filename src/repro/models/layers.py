@@ -31,7 +31,8 @@ def init_norm(cfg, d=None):
     return p
 
 
-def apply_norm(p, x, cfg, eps: float = 1e-6):
+def apply_norm(p, x, cfg, eps=None):
+    eps = cfg.norm_eps if eps is None else eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
         mu = jnp.mean(xf, axis=-1, keepdims=True)
@@ -44,8 +45,17 @@ def apply_norm(p, x, cfg, eps: float = 1e-6):
     return y.astype(x.dtype)
 
 
+def mm(x, w):
+    """x @ w with x cast to the weight's dtype and float32 accumulation,
+    returned in float32: bfloat16 weights multiply bfloat16 inputs on
+    every backend, as the MXU does."""
+    return jnp.matmul(x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
 def rms_head_norm(scale, x, eps: float = 1e-6):
-    """Per-head RMSNorm (qk_norm); x: (..., d_head)."""
+    """RMSNorm over the last axis in float32, returned in x's dtype: the
+    per-head qk_norm (x: (..., d_head)) and MLA's latent norms."""
     xf = x.astype(jnp.float32)
     ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
     return (xf * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)).astype(x.dtype)
@@ -66,6 +76,11 @@ def init_mlp(key, cfg, d_ff=None):
 
 
 def apply_mlp(p, x, cfg):
+    if x.dtype != p["wo"].dtype and cfg.act == "swiglu":
+        # a float32 residual stream over bfloat16 weights: products in
+        # bfloat16, accumulated and activated in float32
+        h = jax.nn.silu(mm(x, p["wg"])) * mm(x, p["wi"])
+        return mm(h, p["wo"]).astype(x.dtype)
     if cfg.act == "swiglu":
         h = jax.nn.silu(x @ p["wg"]) * (x @ p["wi"])
     else:

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts FFN (GShard-style capacity dispatch, sort-based ranks).
+"""Mixture-of-Experts FFN: GShard-style capacity dispatch with sort-based
+ranks, and a dropless dispatch for the DeepSeek-V3 sigmoid router.
 
 Dispatch avoids materializing the (T, k, E) one-hot: expert ranks are computed
 with a sort over the T*k assignment list, tokens are scattered into a dense
@@ -6,6 +7,13 @@ with a sort over the T*k assignment list, tokens are scattered into a dense
 (expert dim shardable over the "model" axis = expert parallelism), and results
 are combined with a weighted scatter-add. Compiled FLOPs ~= activated FLOPs
 times the capacity factor, so roofline numbers stay honest.
+
+Dropless dispatch (``MoEConfig.dropless``): the T*k token-slots are sorted
+by expert, one grouped matmul per projection runs over the group sizes
+(``kernels.ops.grouped_matmul``), and the slots are unsorted and summed
+with their weights. Every token reaches all k of its experts however
+uneven the routing. Under a mesh it runs expert-parallel in the same two
+regimes as the capacity dispatch (``_routed_ep``).
 """
 from __future__ import annotations
 
@@ -14,7 +22,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import dense_init, dtype_of
+from repro.kernels import ops
+from repro.models.layers import dense_init, dtype_of, mm
 
 
 def init_moe(key, cfg):
@@ -33,16 +42,109 @@ def init_moe(key, cfg):
         p["shared_wi"] = dense_init(ks[4], (d, fs), dt)
         p["shared_wg"] = dense_init(ks[5], (d, fs), dt)
         p["shared_wo"] = dense_init(ks[6], (fs, d), dt)
+    if m.dropless:
+        p["router_bias"] = jnp.zeros((e,), jnp.float32)
     return p
+
+
+def route(p, xf, m):
+    """DeepSeek-V3's ``noaux_tc`` router with one group: (expert ids
+    (T, k), weights (T, k), scores (T, E)) of tokens ``xf`` (T, d), all
+    float32. The experts are the top k of ``sigmoid(xf W) + router_bias``;
+    the bias chooses them and does not weight them: the k sigmoid scores
+    are normalised to sum to 1 and scaled by ``routed_scaling``. The
+    router's product runs at the highest precision, so near-ties do not
+    flip on rounding."""
+    z = jnp.dot(xf.astype(jnp.float32), p["router"],
+                precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(z)
+    _, idx = jax.lax.top_k(scores + p["router_bias"], m.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-20) * m.routed_scaling
+    return idx, w, scores
+
+
+def _swiglu_experts(wg, wi, wo):
+    """Grouped SwiGLU of rows sorted by expert: (rows, sizes) -> (rows, d)
+    float32, over the experts of ``wg``/``wi``/``wo``."""
+    def experts(rows, sizes):
+        g = ops.grouped_matmul(rows, wg, sizes)
+        h = jax.nn.silu(g) * ops.grouped_matmul(rows, wi, sizes)
+        return ops.grouped_matmul(h.astype(wo.dtype), wo, sizes)
+    return experts
+
+
+def _routed(xf, idx, w, n_local, experts, lo=None):
+    """(T, d) float32: the weighted sum of each token's k expert outputs.
+    The T*k slots are sorted by expert, ``experts`` runs the grouped
+    matmuls over the ``n_local`` group sizes, and the slots are unsorted
+    and summed with their weights ``w``. With ``lo`` the experts are
+    ``lo .. lo + n_local - 1`` of a larger set: the slots of the others
+    sort after every group, come out of the grouped matmul as zero and
+    add nothing."""
+    t, k = idx.shape
+    d = xf.shape[-1]
+    with jax.named_scope("moe_route"):
+        flat = idx.reshape(t * k)
+        if lo is not None:
+            flat = flat - lo
+            flat = jnp.where((flat >= 0) & (flat < n_local), flat, n_local)
+        order = jnp.argsort(flat)
+        sizes = jnp.bincount(flat, length=n_local).astype(jnp.int32)
+        rows = xf[order // k]
+    with jax.named_scope("moe_experts"):
+        y = experts(rows, sizes)
+    with jax.named_scope("moe_route"):
+        slot_of = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+        # the weighted sum is elementwise: a product here would round to
+        # the MXU's input precision
+        return jnp.sum(y[slot_of].reshape(t, k, d) * w[..., None], axis=1)
+
+
+def _balance_sums(idx, scores, e):
+    """(slots per expert (E,), summed normalised scores (E,)) of a token
+    set: the sums the load-balance loss is made of."""
+    counts = jnp.bincount(idx.reshape(-1), length=e).astype(jnp.float32)
+    return counts, (scores / scores.sum(-1, keepdims=True)).sum(axis=0)
+
+
+def apply_moe_dropless(p, x, cfg, mesh=None, decode=False):
+    """x: (B, S, d) -> (out (B, S, d), aux_loss). Scopes: ``moe_route``
+    (scores, top-k, sort, unsort and the weighted sum), ``moe_experts``
+    (the routed grouped matmuls) and ``moe_shared``. With ``mesh`` the
+    router and the routed experts run expert-parallel (``_routed_ep``)."""
+    m = cfg.moe
+    b, s, d = x.shape
+    t, k, e = b * s, m.top_k, m.n_experts
+    xf = x.reshape(t, d)
+    if mesh is None:
+        with jax.named_scope("moe_route"):
+            idx, w, scores = route(p, xf, m)
+        out = _routed(xf.astype(p["wi"].dtype), idx, w, e,
+                      _swiglu_experts(p["wg"], p["wi"], p["wo"]))
+        counts, p_sum = _balance_sums(idx, scores, e)
+    else:
+        out, counts, p_sum = _routed_ep(p, x, cfg, mesh, decode)
+        out = out.reshape(t, d)
+    if m.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            hs = jax.nn.silu(mm(xf, p["shared_wg"])) * mm(xf, p["shared_wi"])
+            out = out + mm(hs, p["shared_wo"])
+    aux = e * jnp.sum(counts / (t * k) * p_sum / t) * m.router_aux_weight
+    return out.astype(x.dtype).reshape(b, s, d), aux
 
 
 def apply_moe(p, x, cfg):
     """x: (B, S, d) -> (out (B, S, d), aux_loss scalar).
 
-    Under a production mesh (meshctx set) with E % model_size == 0, uses the
-    explicit expert-parallel shard_map path (local per-data-shard dispatch,
-    FSDP weight all-gather, one psum per layer). Otherwise the pure-GSPMD
-    global-dispatch path below (correct everywhere, used by CPU tests)."""
+    Under a production mesh (meshctx set) with E % model_size == 0, the
+    experts run expert-parallel under shard_map: local per-data-shard
+    dispatch, FSDP weight all-gather, one psum per layer; or, for a small
+    token set under FSDP, the decode regime that gathers the tokens
+    instead of the weights. Otherwise the pure-GSPMD global-dispatch path
+    below (correct everywhere, used by CPU tests). A dropless layer takes
+    ``apply_moe_dropless`` in the same regimes."""
     from repro.models import meshctx
     if meshctx.ep_available(cfg):
         mesh = meshctx.get_mesh()
@@ -56,9 +158,15 @@ def apply_moe(p, x, cfg):
             # decode regime: gathering FSDP expert weights per token costs
             # ~params bytes; gather the (tiny) token set instead and contract
             # over the local d-slice of the stationary weights.
+            if cfg.moe.dropless:
+                return apply_moe_dropless(p, x, cfg, mesh, decode=True)
             return apply_moe_ep_decode(p, x, cfg, mesh)
         if x.shape[0] % dp_size == 0:  # shard_map needs batch divisibility
+            if cfg.moe.dropless:
+                return apply_moe_dropless(p, x, cfg, mesh)
             return apply_moe_ep(p, x, cfg, mesh)
+    if cfg.moe.dropless:
+        return apply_moe_dropless(p, x, cfg)
     return _apply_moe_global(p, x, cfg)
 
 
@@ -295,3 +403,70 @@ def apply_moe_ep_decode(p, x, cfg, mesh):
             @ p["shared_wo"]
         out = out + sh.reshape(b, s, d)
     return out, aux
+
+
+def _routed_ep(p, x, cfg, mesh, decode):
+    """The router and ``_routed`` expert-parallel, for the dropless layer:
+    ((B, S, d) float32, the ``_balance_sums`` of all tokens). Each 'model'
+    shard runs the grouped matmul of its own E/model_size experts and the
+    partial sums meet in one psum over 'model', as in ``apply_moe_ep``.
+    Without ``decode`` each data shard routes and sorts its own tokens,
+    with the FSDP weights gathered over 'data'; with ``decode`` every
+    shard takes the whole (small) token set and contracts over its local
+    d-slice of the stationary weights, as in ``apply_moe_ep_decode``."""
+    from jax.sharding import PartitionSpec as P
+
+    m = cfg.moe
+    b, s, d = x.shape
+    dp = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
+    e_loc = m.n_experts // mesh.shape["model"]
+    d_loc = d // mesh.shape["data"]
+    wdt = p["wi"].dtype
+
+    def body(xs, router, bias, wi, wg, wo):
+        lo = jax.lax.axis_index("model").astype(jnp.int32) * e_loc
+        xf = xs.reshape(-1, d)
+        if decode:
+            xf = jax.lax.all_gather(xf, dp, axis=0, tiled=True)
+            di = jax.lax.axis_index("data").astype(jnp.int32) * d_loc
+
+            def experts(rows, sizes):
+                rs = jax.lax.dynamic_slice_in_dim(rows, di, d_loc, axis=1)
+                g = jax.lax.psum(ops.grouped_matmul(rs, wg, sizes), "data")
+                h = jax.nn.silu(g) * jax.lax.psum(
+                    ops.grouped_matmul(rs, wi, sizes), "data")
+                y = ops.grouped_matmul(h.astype(wdt), wo, sizes)
+                return jax.lax.all_gather(y, "data", axis=1, tiled=True)
+        else:
+            if cfg.fsdp:
+                wi = jax.lax.all_gather(wi, "data", axis=1, tiled=True)
+                wg = jax.lax.all_gather(wg, "data", axis=1, tiled=True)
+                wo = jax.lax.all_gather(wo, "data", axis=2, tiled=True)
+            experts = _swiglu_experts(wg, wi, wo)
+        with jax.named_scope("moe_route"):
+            idx, w, scores = route({"router": router, "router_bias": bias},
+                                   xf, m)
+        out = jax.lax.psum(_routed(xf.astype(wdt), idx, w, e_loc, experts,
+                                   lo), "model")
+        counts, p_sum = _balance_sums(idx, scores, m.n_experts)
+        if not decode:
+            return out.reshape(xs.shape), jax.lax.psum(
+                counts, dp), jax.lax.psum(p_sum, dp)
+        # this shard's tokens
+        bi = jax.lax.axis_index(dp[0]) if len(dp) == 1 else (
+            jax.lax.axis_index("pod") * mesh.shape["data"]
+            + jax.lax.axis_index("data"))
+        bl = xs.shape[0]
+        return jax.lax.dynamic_slice_in_dim(
+            out.reshape(-1, s, d), bi.astype(jnp.int32) * bl, bl,
+            axis=0), counts, p_sum
+
+    fsdp = "data" if cfg.fsdp else None
+    wspec_i = P("model", fsdp, None)
+    wspec_o = P("model", None, fsdp)
+    tok = P(dp, None, None)
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(tok, P(None, None), P(None), wspec_i, wspec_i, wspec_o),
+        out_specs=(tok, P(), P()), check_vma=False,
+    )(x, p["router"], p["router_bias"], p["wi"], p["wg"], p["wo"])

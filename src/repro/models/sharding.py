@@ -65,6 +65,10 @@ def param_pspec(path, leaf, cfg, mesh):
     if name in ("wq", "wk", "wv", "wi", "wg", "wx", "wz", "wdt", "wgate",
                 "shared_wi", "shared_wg") and nd == 2:
         return out(fsdp, "model")
+    if name in ("wq_a", "wkv_a") and nd == 2:    # MLA down-projections
+        return out(fsdp, None)
+    if name in ("wq_b", "wkv_b") and nd == 2:    # MLA up-projections (heads)
+        return out(None, "model")
     if name in ("wbc", "conv_bc") and nd == 2:   # head-shared B/C: replicate
         return out(None, None)
     if name in ("wo", "out_proj", "out", "shared_wo") and nd == 2:

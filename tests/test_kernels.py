@@ -55,6 +55,23 @@ def test_bottleneck_encode(t, d, dp, dtype):
     assert int(diff.max()) <= 1  # .5-boundary rounding tolerance
 
 
+@pytest.mark.parametrize("sizes", [(5, 0, 11, 16), (32, 0, 0, 0),
+                                   (3, 4, 5, 6)])
+def test_grouped_matmul_matches_ref(sizes):
+    """Uneven and empty groups; in the last case 14 rows fall past the
+    groups and come out zero."""
+    lhs = jax.random.normal(jax.random.PRNGKey(8), (32, 48), jnp.bfloat16)
+    rhs = jax.random.normal(jax.random.PRNGKey(9), (4, 48, 24), jnp.bfloat16)
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = ops.grouped_matmul(lhs, rhs, gs)
+    want = ref.grouped_matmul_ref(lhs, rhs, gs)
+    assert got.dtype == jnp.float32 and got.shape == (32, 24)
+    # the same bfloat16 products, summed in float32 in another order
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+    assert not np.any(np.asarray(got)[sum(sizes):])
+
+
 @pytest.mark.parametrize("s", [64, 257, 1024])
 @pytest.mark.parametrize("hkv,g", [(2, 4), (1, 8), (4, 1)])
 def test_decode_attention(s, hkv, g):

@@ -174,3 +174,23 @@ def test_quantize_roundtrip_error_bound(bits):
     # round-to-nearest on a uniform grid: error <= half a step everywhere
     # (bound stated as one full step to absorb float32 rounding)
     assert float(jnp.max(jnp.abs(back - x))) <= float(step)
+
+
+def test_moonlight_payload_prices_the_latent():
+    """Moonlight split after its dense front layer: the boundary feature at
+    ratio 4 and 8 bits is 512 B a token, and the UE-side cache shipped with
+    it is layer 0's latent (c_kv and the shared RoPE key)."""
+    from repro.core.split import transformer_split_table
+    cfg = get_config("moonlight-16b-a3b")
+    ctx = 1024
+    # 26 evenly spaced points over 27 layers: the first falls after layer 0
+    prefill = transformer_split_table(cfg, seq_len=ctx, ae_ratio=4,
+                                      quant_bits=8, n_points=26)
+    assert prefill.points[0] == 1
+    assert prefill.f_bits[1] / ctx / 8 == 512
+    plan = llm_decode_split_table(cfg, ctx, ae_ratio=4, quant_bits=8,
+                                  n_points=26)
+    assert plan.points[0] == 1
+    latent = entry_payload_bits(cfg, cfg.block_types()[0], 1, ctx)
+    assert latent == ctx * (512 + 64 + 1) * 32
+    assert plan.f_bits[1] == ctx * 512 * 8 + latent

@@ -171,3 +171,57 @@ def test_edge_step_bn_stats_fuse_into_producers(one_chip, arch):
             reduce_only.append(m.group(1))
     assert reduce_only == []
     assert "multiply_reduce_fusion" not in text
+
+
+LLM_SCOPES = ("dequantize", "ae_decode", "mla", "moe_route", "moe_experts",
+              "moe_shared", "lm_head")
+
+
+def test_llm_edge_step_compiles(one_chip, monkeypatch):
+    """The transformer edge step (``bench/entries/llm_edge.py``) at a small
+    width: dequantize kernel, decode, two MLA + MoE layers with the routed
+    experts' grouped matmul, the head. Every layer keeps its named scope
+    but the grouped matmul, whose TPU expansion XLA names
+    ``ragged-dot-…`` and gives no scope (``bench/layer_scopes.py`` finds
+    it by that name)."""
+    import json
+    import os
+
+    from bench import harness
+    from repro.core import compressor
+    from repro.models import model
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    entry = harness.load_module(os.path.join(bench, "entries",
+                                             "llm_edge.py"))
+    ref = harness.load_module(os.path.join(bench, "reference",
+                                           "moonlight.py"))
+    with open(os.path.join(bench, "configs",
+                           "moonlight16b-a3b-edge.json")) as f:
+        c = json.load(f)
+    c.update(hidden_size=256, num_attention_heads=4, kv_lora_rank=128,
+             qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=64,
+             moe_intermediate_size=128, n_routed_experts=8,
+             num_experts_per_tok=2, n_shared_experts=1, num_hidden_layers=3,
+             vocab_size=1024)
+    cfg = entry.program_config(c)
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+
+    def step(params, dec, codes, mn, mx):
+        z = ops.dequantize(codes, mn, mx, bits=8, impl="pallas")
+        feat = compressor.decode({"dec": dec}, z)
+        return model.edge_forward(entry.program_params(params), cfg, feat)
+
+    edge = jax.tree_util.tree_map(
+        lambda s: _spec(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda k: ref.init_edge(k, c), jax.random.PRNGKey(0)))
+    text = jax.jit(step).lower(
+        edge, _spec(one_chip, (64, 256)),
+        _spec(one_chip, (2, 128, 64), jnp.uint8), _spec(one_chip, ()),
+        _spec(one_chip, ())).compile().as_text()
+    assert "tpu_custom_call" in text
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    found = {s for n in names for s in n.split(":")[0].split("/")}
+    assert set(LLM_SCOPES) <= found
+    assert any(n.startswith("ragged-dot") for n in names)
