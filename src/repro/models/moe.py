@@ -80,17 +80,20 @@ def _routed(xf, idx, w, n_local, experts, lo=None):
     matmuls over the ``n_local`` group sizes, and the slots are unsorted
     and summed with their weights ``w``. With ``lo`` the experts are
     ``lo .. lo + n_local - 1`` of a larger set: the slots of the others
-    sort after every group, come out of the grouped matmul as zero and
+    sort after every group, are counted last in the sizes (the grouped
+    matmul's form for rows past its groups), come out of it as zero and
     add nothing."""
     t, k = idx.shape
     d = xf.shape[-1]
     with jax.named_scope("moe_route"):
         flat = idx.reshape(t * k)
+        counts = n_local
         if lo is not None:
             flat = flat - lo
             flat = jnp.where((flat >= 0) & (flat < n_local), flat, n_local)
+            counts += 1
         order = jnp.argsort(flat)
-        sizes = jnp.bincount(flat, length=n_local).astype(jnp.int32)
+        sizes = jnp.bincount(flat, length=counts).astype(jnp.int32)
         rows = xf[order // k]
     with jax.named_scope("moe_experts"):
         y = experts(rows, sizes)
@@ -413,7 +416,10 @@ def _routed_ep(p, x, cfg, mesh, decode):
     Without ``decode`` each data shard routes and sorts its own tokens,
     with the FSDP weights gathered over 'data'; with ``decode`` every
     shard takes the whole (small) token set and contracts over its local
-    d-slice of the stationary weights, as in ``apply_moe_ep_decode``."""
+    d-slice of the stationary weights, as in ``apply_moe_ep_decode``.
+    ``_routed`` counts the other shards' slots last in the group sizes, so
+    that the grouped matmul returns zero for them: its (g,) form must
+    cover every row, and the psums would add rows it left undefined."""
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe

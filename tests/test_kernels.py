@@ -55,21 +55,137 @@ def test_bottleneck_encode(t, d, dp, dtype):
     assert int(diff.max()) <= 1  # .5-boundary rounding tolerance
 
 
-@pytest.mark.parametrize("sizes", [(5, 0, 11, 16), (32, 0, 0, 0),
-                                   (3, 4, 5, 6)])
-def test_grouped_matmul_matches_ref(sizes):
-    """Uneven and empty groups; in the last case 14 rows fall past the
-    groups and come out zero."""
-    lhs = jax.random.normal(jax.random.PRNGKey(8), (32, 48), jnp.bfloat16)
-    rhs = jax.random.normal(jax.random.PRNGKey(9), (4, 48, 24), jnp.bfloat16)
-    gs = jnp.asarray(sizes, jnp.int32)
-    got = ops.grouped_matmul(lhs, rhs, gs)
-    want = ref.grouped_matmul_ref(lhs, rhs, gs)
-    assert got.dtype == jnp.float32 and got.shape == (32, 24)
+def _grouped_inputs(m, k, n, g):
+    lhs = jax.random.normal(jax.random.PRNGKey(8), (m, k), jnp.bfloat16)
+    rhs = jax.random.normal(jax.random.PRNGKey(9), (g, k, n), jnp.bfloat16)
+    return lhs, rhs
+
+
+def _check_grouped(got, lhs, rhs, sizes):
+    """``got`` against the oracle over the groups' rows; rows past them
+    zero."""
+    m, n = lhs.shape[0], rhs.shape[2]
+    want = ref.grouped_matmul_ref(lhs, rhs,
+                                  jnp.asarray(sizes[:rhs.shape[0]]))
+    assert got.dtype == jnp.float32 and got.shape == (m, n)
     # the same bfloat16 products, summed in float32 in another order
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
-    assert not np.any(np.asarray(got)[sum(sizes):])
+    assert not np.any(np.asarray(got)[sum(sizes[:rhs.shape[0]]):])
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("m,n,sizes", [
+    (32, 24, (5, 0, 11, 16)), (32, 24, (32, 0, 0, 0)),
+    (32, 24, (3, 4, 5, 6)), (1100, 24, (300, 0, 500, 300)),
+    (32, 600, (5, 0, 11, 16))])
+def test_grouped_matmul_matches_ref(m, n, sizes, impl):
+    """Uneven and empty groups, through ``ops.grouped_matmul``'s own
+    tiling (Pallas in interpret mode). Where 14 rows fall past the groups
+    a last count names them, and they come out zero. At 1100 rows m is
+    padded to a multiple of ``gmm.TM`` and the group boundaries at 300
+    and 800 fall inside m-tiles; n = 600 leaves a partial last n-tile
+    after one of ``gmm.TN``."""
+    lhs, rhs = _grouped_inputs(m, 48, n, 4)
+    if sum(sizes) < m:
+        sizes += (m - sum(sizes),)
+    got = ops.grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32),
+                             impl=impl)
+    _check_grouped(got, lhs, rhs, sizes)
+
+
+@pytest.mark.parametrize("k,n,sizes,tiles", [
+    # a boundary inside each of two 64-row m-tiles, an empty group
+    (128, 256, (37, 0, 100, 119), (64, 128, 128)),
+    # n = 300 is not a multiple of tn = 128: a partial last n-tile
+    (128, 300, (37, 0, 100, 119), (64, 128, 128)),
+    # k = 300 in 128-wide k-tiles: the last one is masked
+    (300, 256, (64, 64, 0, 128), (64, 128, 128)),
+    # 9 rows past the groups, counted last: they come out zero
+    (128, 300, (37, 0, 100, 110, 9), (64, 128, 128)),
+])
+def test_gmm_kernel_tiles(k, n, sizes, tiles):
+    """The Pallas kernel at tiles smaller than the shape, in interpret
+    mode, against the oracle."""
+    from repro.kernels import gmm
+
+    lhs, rhs = _grouped_inputs(256, k, n, 4)
+    got = gmm.gmm(lhs, rhs, jnp.asarray(sizes, jnp.int32), tiles, True)
+    _check_grouped(got, lhs, rhs, sizes)
+
+
+@pytest.mark.parametrize("sizes", [(5, 0, 11, 16), (3, 4, 5, 6, 14)])
+def test_grouped_matmul_grad_matches_ref(sizes):
+    """The gradient through the Pallas path (interpret mode) is the
+    oracle's, for the rows and the weights; rows past the groups get
+    none."""
+    lhs, rhs = _grouped_inputs(32, 48, 24, 4)
+    gs = jnp.asarray(sizes, jnp.int32)
+    cot = jax.random.normal(jax.random.PRNGKey(10), (32, 24))
+
+    def loss(f):
+        return lambda x, w: jnp.sum(f(x, w) * cot)
+
+    got = jax.grad(loss(lambda x, w: ops.grouped_matmul(
+        x, w, gs, impl="pallas", interpret=True)), argnums=(0, 1))(lhs, rhs)
+    want = jax.grad(loss(lambda x, w: ref.grouped_matmul_ref(
+        x, w, gs[:4])), argnums=(0, 1))(lhs, rhs)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+    assert not np.any(np.asarray(got[0], np.float32)[sum(sizes[:4]):])
+
+
+def test_routed_expert_shards_sum_to_all_experts(monkeypatch):
+    """``moe._routed`` over two shards of two experts each (``lo`` 0 and
+    2), on the interpreted Pallas kernel, sums to all four experts on
+    ``ragged_dot``: each shard's kernel clears the other's slots."""
+    from repro.models import moe
+
+    t, k, d, f = 40, 2, 32, 48
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    xf = jax.random.normal(keys[0], (t, d), jnp.bfloat16)
+    idx = jax.random.randint(keys[1], (t, k), 0, 4)
+    w = jax.random.uniform(keys[2], (t, k))
+    wg, wi = (jax.random.normal(kk, (4, d, f), jnp.bfloat16) * 0.2
+              for kk in jax.random.split(keys[3]))
+    wo = jax.random.normal(keys[4], (4, f, d), jnp.bfloat16) * 0.2
+
+    def shard(lo, n):
+        return moe._swiglu_experts(wg[lo:lo + n], wi[lo:lo + n],
+                                   wo[lo:lo + n])
+
+    want = moe._routed(xf, idx, w, 4, shard(0, 4))
+    monkeypatch.setattr(ops, "_backend_impl", lambda: "pallas")
+    got = sum(moe._routed(xf, idx, w, 2, shard(lo, 2), lo=lo)
+              for lo in (0, 2))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("m,k,n", [
+    (98304, 2048, 1408), (98304, 1408, 2048),   # the Moonlight cell
+    (16384, 7168, 2048), (16384, 2048, 7168),   # Kimi K2's experts
+    (8, 1792, 2048),                            # K2 decode, a d-slice of 4
+    (32, 48, 24), (1100, 48, 24), (32, 48, 600), (256, 32, 16)])  # tests
+def test_gmm_tiling_fits_the_shape(m, k, n):
+    """Every tile is the whole dimension or a multiple of the layout's
+    (16 rows, 128 lanes) below it, and the blocks fit the VMEM budget, in
+    bfloat16 and in float32. The Moonlight cell's shapes take the whole
+    of k, so each expert's weights are fetched once, and the sweep's
+    (256, k, 512)."""
+    from repro.kernels import gmm
+
+    for itemsize in (2, 4):
+        tm, tk, tn = gmm.tiling(m, k, n, itemsize)
+        assert tm % 16 == 0 and tm <= min(gmm.TM, m + 15)
+        assert tk == k or (tk % 128 == 0 and tk < k)
+        assert tn == n or (tn % 128 == 0 and tn < n)
+        assert gmm.vmem_bytes(tm, tk, tn, itemsize) <= gmm.VMEM_BUDGET
+    if m == 98304:
+        assert gmm.tiling(m, k, n) == (256, k, 512)
 
 
 @pytest.mark.parametrize("s", [64, 257, 1024])

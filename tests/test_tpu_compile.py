@@ -5,8 +5,9 @@ accelerator is needed, but the TPU compiler refuses here what interpret mode
 accepts: an unsupported cast, a misaligned tile, too much VMEM. Each test
 lowers one kernel through ``kernels.ops`` at a main-path width and asserts
 that the program holds the Mosaic kernel (``tpu_custom_call``). The last
-two compile the benchmark's edge step and check that its layers keep their
-named scopes, and that BatchNorm's statistics fuse into the convolutions.
+ones compile the benchmark's edge steps and check that their layers keep
+their named scopes, that BatchNorm's statistics fuse into the
+convolutions, and that the routed experts run the Pallas grouped matmul.
 
 The topology is described inside a fixture, never at import: one process at
 a time may load the TPU library, and every test worker imports this file.
@@ -173,6 +174,44 @@ def test_edge_step_bn_stats_fuse_into_producers(one_chip, arch):
     assert "multiply_reduce_fusion" not in text
 
 
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_grouped_matmul_compiles(one_chip, k, n):
+    """The routed experts' grouped matmul at the Moonlight cell's full
+    shapes: 98,304 routed rows (16 x 1024 tokens x top 6), 64 experts, in
+    ``gmm.tiling``'s tiles, whose blocks must fit the VMEM limit the
+    kernel sets."""
+    _assert_mosaic(lambda x, w, s: ops.grouped_matmul(
+        x, w, s, impl="pallas", interpret=False),
+        _spec(one_chip, (98304, k), jnp.bfloat16),
+        _spec(one_chip, (64, k, n), jnp.bfloat16),
+        _spec(one_chip, (64,), jnp.int32))
+
+
+def test_dropless_train_step_compiles(one_chip, monkeypatch):
+    """A training step of Moonlight at a small size (two layers, the
+    second a dropless MoE layer, bfloat16 weights) with the Pallas
+    grouped matmul: its forward is the Mosaic kernel, and its gradient
+    compiles (``ragged_dot``'s VJP)."""
+    from repro.configs import get_config
+    from repro.configs.base import reduced
+    from repro.launch.steps import make_train_step
+    from repro.models import init_params
+
+    cfg = reduced(get_config("moonlight-16b-a3b"), d_model=256,
+                  vocab=512).replace(param_dtype="bfloat16")
+    assert cfg.moe.dropless
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    monkeypatch.setattr(ops, "_backend_impl", lambda: "pallas")
+    train_step, opt_init = make_train_step(cfg)
+    spec = lambda s: _spec(one_chip, s.shape, s.dtype)
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    opt = jax.eval_shape(opt_init, params)
+    tokens = _spec(one_chip, (2, 128), jnp.int32)
+    _assert_mosaic(train_step, *jax.tree_util.tree_map(spec, (params, opt)),
+                   {"tokens": tokens, "labels": tokens})
+
+
 LLM_SCOPES = ("dequantize", "ae_decode", "mla", "moe_route", "moe_experts",
               "moe_shared", "lm_head")
 
@@ -180,10 +219,9 @@ LLM_SCOPES = ("dequantize", "ae_decode", "mla", "moe_route", "moe_experts",
 def test_llm_edge_step_compiles(one_chip, monkeypatch):
     """The transformer edge step (``bench/entries/llm_edge.py``) at a small
     width: dequantize kernel, decode, two MLA + MoE layers with the routed
-    experts' grouped matmul, the head. Every layer keeps its named scope
-    but the grouped matmul, whose TPU expansion XLA names
-    ``ragged-dot-…`` and gives no scope (``bench/layer_scopes.py`` finds
-    it by that name)."""
+    experts' grouped matmul, the head. Every layer keeps its named scope,
+    and the grouped matmul is the Pallas kernel, a Mosaic call under
+    ``moe_experts``: no XLA ``ragged-dot`` is left."""
     import json
     import os
 
@@ -206,7 +244,9 @@ def test_llm_edge_step_compiles(one_chip, monkeypatch):
              num_experts_per_tok=2, n_shared_experts=1, num_hidden_layers=3,
              vocab_size=1024)
     cfg = entry.program_config(c)
+    # what the program chooses on the chip, where this process sees a CPU
     monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    monkeypatch.setattr(ops, "_backend_impl", lambda: "pallas")
 
     def step(params, dec, codes, mn, mx):
         z = ops.dequantize(codes, mn, mx, bits=8, impl="pallas")
@@ -220,8 +260,13 @@ def test_llm_edge_step_compiles(one_chip, monkeypatch):
         edge, _spec(one_chip, (64, 256)),
         _spec(one_chip, (2, 128, 64), jnp.uint8), _spec(one_chip, ()),
         _spec(one_chip, ())).compile().as_text()
-    assert "tpu_custom_call" in text
     names = set(re.findall(r'op_name="([^"]*)"', text))
     found = {s for n in names for s in n.split(":")[0].split("/")}
     assert set(LLM_SCOPES) <= found
-    assert any(n.startswith("ragged-dot") for n in names)
+    assert not any("ragged-dot" in n for n in names)
+    assert "ragged-dot" not in text
+    mosaic = [re.search(r'op_name="([^"]*)"', l)
+              for l in text.splitlines() if "tpu_custom_call" in l]
+    experts = [m.group(1) for m in mosaic
+               if m and "moe_experts" in m.group(1).split("/")]
+    assert experts, [m and m.group(1) for m in mosaic]
